@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -123,7 +121,7 @@ def flash_attention(q, k, v, *, q_offset: int = 0, causal: bool = True,
             pltpu.VMEM((G * block_q,), jnp.float32),
             pltpu.VMEM((G * block_q, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qg, kk, vv)

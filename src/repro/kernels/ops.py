@@ -1,10 +1,15 @@
 """Jitted public wrappers for the Pallas kernels.
 
-Backend selection: on TPU the compiled kernels run natively; elsewhere
-(this CPU container) they execute in ``interpret=True`` mode, which runs the
-kernel body in Python/XLA-CPU for correctness validation. ``use_reference``
-forces the pure-jnp oracle (fastest on CPU — the model code defaults to it
-off-TPU).
+The model code does not call these yet: ``repro.models`` computes
+attention and the recurrences in plain ``jax.numpy``. The wrappers are
+exercised by the kernel tests, which run them with ``interpret=True`` on the
+CPU against the oracles in ``repro.kernels.ref``, and by
+``tests/test_tpu_compile.py``, which compiles them for a described TPU v5e.
+
+``interpret`` is an explicit static argument, default ``False``: a wrapper
+never infers it from the backend, so code that meant to run the compiled
+kernel fails off the chip instead of silently running the interpreter.
+``use_reference`` runs the pure-jnp oracle instead of the kernel.
 """
 from __future__ import annotations
 
@@ -12,7 +17,6 @@ import functools
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention as _decode_pl
@@ -21,42 +25,37 @@ from repro.kernels.rglru import rglru_scan as _rglru_pl
 from repro.kernels.wkv6 import wkv6 as _wkv6_pl
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _interpret() -> bool:
-    return not on_tpu()
-
-
 @functools.partial(jax.jit, static_argnames=("q_offset", "causal", "window",
-                                             "use_reference"))
+                                             "use_reference", "interpret"))
 def flash_attention(q, k, v, *, q_offset: int = 0, causal: bool = True,
-                    window: Optional[int] = None, use_reference: bool = False):
+                    window: Optional[int] = None, use_reference: bool = False,
+                    interpret: bool = False):
     if use_reference:
         return ref.flash_attention_ref(q, k, v, q_offset=q_offset,
                                        causal=causal, window=window)
     return _flash_pl(q, k, v, q_offset=q_offset, causal=causal,
-                     window=window, interpret=_interpret())
+                     window=window, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("use_reference",))
+@functools.partial(jax.jit, static_argnames=("use_reference", "interpret"))
 def decode_attention(q, k_cache, v_cache, valid, *,
-                     use_reference: bool = False):
+                     use_reference: bool = False, interpret: bool = False):
     if use_reference:
         return ref.decode_attention_ref(q, k_cache, v_cache, valid)
-    return _decode_pl(q, k_cache, v_cache, valid, interpret=_interpret())
+    return _decode_pl(q, k_cache, v_cache, valid, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("use_reference",))
-def rglru_scan(a, b, h0, *, use_reference: bool = False):
+@functools.partial(jax.jit, static_argnames=("use_reference", "interpret"))
+def rglru_scan(a, b, h0, *, use_reference: bool = False,
+               interpret: bool = False):
     if use_reference:
         return ref.rglru_scan_ref(a, b, h0)
-    return _rglru_pl(a, b, h0, interpret=_interpret())
+    return _rglru_pl(a, b, h0, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("use_reference",))
-def wkv6(r, k, v, w, u, s0, *, use_reference: bool = False):
+@functools.partial(jax.jit, static_argnames=("use_reference", "interpret"))
+def wkv6(r, k, v, w, u, s0, *, use_reference: bool = False,
+         interpret: bool = False):
     if use_reference:
         return ref.wkv6_ref(r, k, v, w, u, s0)
-    return _wkv6_pl(r, k, v, w, u, s0, interpret=_interpret())
+    return _wkv6_pl(r, k, v, w, u, s0, interpret=interpret)

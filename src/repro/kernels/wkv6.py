@@ -17,14 +17,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sn_ref,
                 state_ref, *, seq_len: int):
     state_ref[...] = s0_ref[0]
 
-    u = u_ref[0]                                    # (hd,)
+    u = u_ref[0, 0]                                 # (hd,)
 
     def body(t, _):
         rt = r_ref[0, t, :]
@@ -49,7 +47,9 @@ def wkv6(r, k, v, w, u, s0, *, interpret: bool = False):
     kk = k.reshape(B * H, S, hd)
     vv = v.reshape(B * H, S, hd)
     ww = w.reshape(B * H, S, hd)
-    uu = jnp.broadcast_to(u[None], (B, H, hd)).reshape(B * H, hd)
+    # u rides as (B*H, 1, hd): a (1, hd) block over (B*H, hd) breaks the TPU
+    # rule that a block's last two dims are (8, 128)-aligned or whole
+    uu = jnp.broadcast_to(u[None], (B, H, hd)).reshape(B * H, 1, hd)
     ss = s0.reshape(B * H, hd, hd)
 
     y, sn = pl.pallas_call(
@@ -60,7 +60,7 @@ def wkv6(r, k, v, w, u, s0, *, interpret: bool = False):
             pl.BlockSpec((1, S, hd), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, S, hd), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, S, hd), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, hd), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, hd), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, hd, hd), lambda i: (i, 0, 0)),
         ],
         out_specs=[
@@ -72,7 +72,7 @@ def wkv6(r, k, v, w, u, s0, *, interpret: bool = False):
             jax.ShapeDtypeStruct((B * H, hd, hd), r.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(rr, kk, vv, ww, uu, ss)

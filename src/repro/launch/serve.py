@@ -25,7 +25,11 @@ that build the equivalent ``--plan`` candidates.
 from __future__ import annotations
 
 import argparse
+import os
+import time
 import warnings
+from dataclasses import dataclass
+from pathlib import Path
 
 import numpy as np
 
@@ -226,34 +230,195 @@ def write_observability(args, ctl, res):
         print(f"  carbon ledger:  audited, {cuts}")
 
 
-def run_real(args):
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+# JAX monitoring events that make up a compile: trace, lower, and the
+# backend compile, which fires once per program whether it is compiled or
+# read back from the persistent cache
+_COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                   "/jax/core/compile/backend_compile_duration")
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call before the first
+    compile. ``JAX_COMPILATION_CACHE_DIR``, where set, wins: JAX reads it
+    itself and nothing is set here. Otherwise the cache is the fixed
+    ``<checkout>/.jax_cache``, so that a later run finds it again."""
     import jax
-    import jax.numpy as jnp
-    from repro.configs import get_config
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+SESSIONS = ("s0", "s1")
+
+
+@dataclass(frozen=True)
+class SessionTraffic:
+    """Two two-turn conversations (``SESSIONS``), then one unshared control
+    request. Turn 2 is turn 1's prompt, its output and ``followup_len`` new
+    user tokens, so it reuses turn 1's ``prompt_len`` cached tokens; the
+    control request must miss."""
+    prompt_len: int
+    followup_len: int
+    control_len: int
+    num_new: int            # tokens generated per request
+
+    def store_bytes(self, cfg) -> int:
+        """Store capacity that holds every prompt of the traffic at once:
+        each session's turn-2 prompt (which extends its turn-1 entry) and
+        the control prompt, at the store's bytes per token for ``cfg``."""
+        held = (len(SESSIONS) * (self.prompt_len + self.num_new
+                                 + self.followup_len) + self.control_len)
+        return held * max(cfg.kv_bytes_per_token, 1)
+
+
+@dataclass
+class RealRun:
+    engine: "RealExecutionEngine"
+    prompts: dict               # request name -> prompt tokens
+    warmup: dict                # request name -> GenerationResult
+    results: dict               # the measured pass, same names
+    setup_s: float              # wall time of init and the warm-up pass
+    setup: "CompileClock"       # compiles during init and the warm-up
+    window: "CompileClock"      # compiles during the measured pass
+
+
+class CompileClock:
+    """Counts programs compiled (or read back from the persistent cache)
+    and sums their trace + lower + compile seconds, from JAX's own
+    monitoring events, while in a ``with`` block."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.programs = 0
+        self.cache_hits = 0
+
+    def _on_duration(self, event, duration, **_):
+        if event in _COMPILE_EVENTS:
+            self.seconds += duration
+            self.programs += event == _COMPILE_EVENTS[-1]
+
+    def _on_event(self, event, **_):
+        self.cache_hits += event == _CACHE_HIT_EVENT
+
+    def __enter__(self):
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+        return self
+
+    def __exit__(self, *exc):
+        import jax
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
+        jax.monitoring.unregister_event_listener(self._on_event)
+
+
+def _serve(eng, traffic: SessionTraffic, seed: int):
+    """Serve ``traffic`` on ``eng``; prompts are drawn from ``seed``."""
+    rng = np.random.default_rng(seed)
+    draw = lambda n: [int(t) for t in
+                      rng.integers(0, eng.cfg.vocab_size, size=n)]
+    turn1 = {s: draw(traffic.prompt_len) for s in SESSIONS}
+    followup = {s: draw(traffic.followup_len) for s in SESSIONS}
+    prompts = {f"{s}/turn1": turn1[s] for s in SESSIONS}
+    out = {n: eng.generate(n.split("/")[0], p, num_new=traffic.num_new)
+           for n, p in prompts.items()}
+    for s in SESSIONS:
+        p = turn1[s] + out[f"{s}/turn1"].tokens + followup[s]
+        prompts[f"{s}/turn2"] = p
+        out[f"{s}/turn2"] = eng.generate(s, p, num_new=traffic.num_new)
+    prompts["control"] = draw(traffic.control_len)
+    out["control"] = eng.generate("control", prompts["control"],
+                                  num_new=traffic.num_new)
+    return prompts, out
+
+
+def run_real(cfg, traffic: SessionTraffic, *, dtype, max_len: int,
+             store_bytes: float, seed: int = 0) -> RealRun:
+    """Serve ``traffic`` with a random-weight ``cfg`` behind a GreenCache
+    ``KVStore`` of ``store_bytes``, through ``RealExecutionEngine``.
+
+    The traffic runs twice on the same engine, each time on an empty
+    store: a warm-up pass that compiles every shape, then the measured
+    pass, whose results are returned. The same seed gives both passes the
+    same prompts, so the measured pass should compile nothing."""
+    import jax
     from repro.core.kvstore import KVStore
     from repro.core.policies import POLICIES
     from repro.models.transformer import init_params
     from repro.serving.realexec import RealExecutionEngine
 
-    cfg = get_config(args.arch).reduced(num_layers=2, d_model=128)
-    params = init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
-    store = KVStore(64e6, POLICIES["lcs"],
-                    max(cfg.kv_bytes_per_token, 1))
-    eng = RealExecutionEngine(cfg, params, store, max_len=128)
-    rng = np.random.default_rng(0)
-    ctx = [int(t) for t in rng.integers(0, cfg.vocab_size, size=24)]
+    def store():
+        return KVStore(store_bytes, POLICIES["lcs"],
+                       max(cfg.kv_bytes_per_token, 1))
 
-    r1 = eng.generate("conv-0", ctx, num_new=4)
-    print(f"turn 1: computed {r1.prefill_tokens_computed} prefill tokens, "
-          f"reused {r1.reused_tokens} -> {r1.tokens}")
-    ctx2 = ctx + r1.tokens + [int(t) for t in
-                              rng.integers(0, cfg.vocab_size, size=8)]
-    r2 = eng.generate("conv-0", ctx2, num_new=4)
-    print(f"turn 2: computed {r2.prefill_tokens_computed} prefill tokens, "
-          f"reused {r2.reused_tokens} -> {r2.tokens}")
-    assert r2.reused_tokens > 0, "expected a cache hit on turn 2"
+    t0 = time.perf_counter()
+    with CompileClock() as setup:
+        params = jax.block_until_ready(
+            init_params(jax.random.PRNGKey(seed), cfg, dtype))
+        eng = RealExecutionEngine(cfg, params, store(), max_len=max_len,
+                                  dtype=dtype)
+        _, warmup = _serve(eng, traffic, seed)
+    setup_s = time.perf_counter() - t0
+    eng.store = store()
+    with CompileClock() as window:
+        prompts, results = _serve(eng, traffic, seed)
+    for name, r in results.items():
+        print(f"{name}: reused {r.reused_tokens} computed "
+              f"{r.prefill_tokens_computed} prefill_s {r.prefill_time_s} "
+              f"decode_s {r.decode_time_s} -> {r.tokens[:8]}")
+    return RealRun(eng, prompts, warmup, results, setup_s, setup, window)
+
+
+def compare_hit_prefill(eng, prompt, prefix_len: int) -> dict:
+    """Prefill ``prompt`` the way a cache hit does (its first
+    ``prefix_len`` tokens, then the rest after their cached KV) and in one
+    full pass, through ``eng``'s own programs; compare the logits of the
+    suffix positions. Returns the relative L2 error, the largest absolute
+    difference, the share of positions whose argmax agrees, and whether
+    both are finite."""
+    import jax.numpy as jnp
+
+    toks = jnp.asarray(prompt, jnp.int32)[None]
+    _, prefix_cache = eng.prefill(toks[:, :prefix_len])
+    hit, _ = eng.prefill(toks[:, prefix_len:], prefix_cache, prefix_len)
+    full, _ = eng.prefill(toks)
+    V = eng.cfg.vocab_size
+    hit = hit[0, :, :V].astype(jnp.float32)
+    ref = full[0, prefix_len:, :V].astype(jnp.float32)
+    return {
+        "rel_l2": float(jnp.linalg.norm(hit - ref) / jnp.linalg.norm(ref)),
+        "max_abs": float(jnp.max(jnp.abs(hit - ref))),
+        "argmax_agree": float(jnp.mean(jnp.argmax(hit, -1)
+                                       == jnp.argmax(ref, -1))),
+        "finite": bool(jnp.isfinite(hit).all() & jnp.isfinite(ref).all()),
+    }
+
+
+def run_real_demo(args):
+    """``--real``: a reduced model on the CPU; turn 2 must hit."""
+    import jax.numpy as jnp
+    from repro.configs import get_config
+
+    use_compile_cache()
+    cfg = get_config(args.arch).reduced(num_layers=2, d_model=128)
+    traffic = SessionTraffic(prompt_len=24, followup_len=8, control_len=12,
+                             num_new=4)
+    run = run_real(cfg, traffic, dtype=jnp.float32, max_len=128,
+                   store_bytes=traffic.store_bytes(cfg))
+    r2 = run.results["s0/turn2"]
+    if r2.reused_tokens != traffic.prompt_len:
+        raise SystemExit(f"expected turn 2 to reuse {traffic.prompt_len} "
+                         f"cached tokens, it reused {r2.reused_tokens}")
     print("cache hit verified: suffix-only prefill")
-    return r2
+    return run
 
 
 def main(argv=None):
@@ -360,7 +525,7 @@ def main(argv=None):
     ap.add_argument("--arch", default="yi-6b")
     args = ap.parse_args(argv)
     if args.real:
-        return run_real(args)
+        return run_real_demo(args)
     return run_simulation(args)
 
 

@@ -17,6 +17,7 @@ remat, so compiled HLO stays one-layer-sized for the multi-pod dry-run.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -304,7 +305,11 @@ def _dec_layer_decode(p, cfg, x_t, sk, sv, ck, cv, pos, *, window=None):
 # top level: init
 # --------------------------------------------------------------------------- #
 
+@functools.partial(jax.jit, static_argnames=("cfg", "dtype"))
 def init_params(key, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
+    """Random parameters, built as one program: each weight is drawn in
+    float32 and cast inside a fusion, so no float32 copy of a whole layer
+    stack is ever held (8B-class stacks would not fit on the device)."""
     ks = jax.random.split(key, 8)
     V, d = cfg.padded_vocab, cfg.d_model
     p: Params = {

@@ -1,7 +1,8 @@
 """Real-execution serving: an actual JAX model behind the GreenCache store.
 
 This is the paper's mechanism running for real (at reduced scale on CPU,
-full scale on TPU), where the rest of the repo simulates it analytically:
+published widths on TPU; see ``chip_smoke.py``), where the rest of the repo
+simulates it analytically:
 
 * ``generate(context_key, tokens, num_new)`` looks the context prefix up
   in the same ``repro.core.kvstore.KVStore`` the simulator uses. The KV
@@ -59,8 +60,11 @@ class RealExecutionEngine:
             lambda p, c, t, pos: decode_step(p, cfg, c, t, pos))
 
     # ------------------------------------------------------------------ #
-    def _prefill(self, tokens: jnp.ndarray, prefix_cache=None,
-                 prefix_len: int = 0):
+    def prefill(self, tokens: jnp.ndarray, prefix_cache=None,
+                prefix_len: int = 0):
+        """Prefill ``tokens`` (1, S) after a cached prefix of ``prefix_len``
+        tokens, through one jitted program per (S, prefix_len). Returns
+        (logits (1, S, V), cache)."""
         key = (tokens.shape[1], prefix_len)
         if key not in self._prefill_cached:
             cfgl = self.cfg
@@ -93,7 +97,7 @@ class RealExecutionEngine:
             if plen <= len(prompt_tokens):
                 prefix_len, prefix_cache = plen, pcache
 
-        t0 = time.time()
+        t0 = time.perf_counter()
         if recurrent:
             # state-snapshot caching: restore state, run the suffix through
             # decode steps (prefill from state not implemented for brevity —
@@ -113,16 +117,17 @@ class RealExecutionEngine:
         else:
             suffix = jnp.asarray(prompt_tokens[prefix_len:],
                                  jnp.int32)[None]
-            logits, cache = self._prefill(suffix, prefix_cache, prefix_len)
+            logits, cache = self.prefill(suffix, prefix_cache, prefix_len)
             pos = len(prompt_tokens)
-        t_prefill = time.time() - t0
+        logits.block_until_ready()      # time the device, not the dispatch
+        t_prefill = time.perf_counter() - t0
 
         # store the full-prompt cache back (extends the prefix entry)
         self.store.insert(context_key, len(prompt_tokens), now,
                           payload=(len(prompt_tokens), cache))
 
         # greedy decode
-        t1 = time.time()
+        t1 = time.perf_counter()
         out = []
         tok = int(jnp.argmax(logits[0, -1, :self.cfg.vocab_size]))
         for _ in range(num_new):
@@ -137,4 +142,4 @@ class RealExecutionEngine:
             prefill_tokens_computed=len(prompt_tokens) - prefix_len,
             reused_tokens=prefix_len,
             prefill_time_s=t_prefill,
-            decode_time_s=time.time() - t1)
+            decode_time_s=time.perf_counter() - t1)

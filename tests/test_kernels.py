@@ -31,7 +31,8 @@ def test_flash_attention_sweep(dtype, B, H, KV, Sq, Sk, hd, off, win):
     q = rand(ks[0], (B, H, Sq, hd), dtype)
     k = rand(ks[1], (B, KV, Sk, hd), dtype)
     v = rand(ks[2], (B, KV, Sk, hd), dtype)
-    a = ops.flash_attention(q, k, v, q_offset=off, window=win)
+    a = ops.flash_attention(q, k, v, q_offset=off, window=win,
+                            interpret=True)
     b = R.flash_attention_ref(q, k, v, q_offset=off, window=win)
     np.testing.assert_allclose(np.asarray(a, np.float32),
                                np.asarray(b, np.float32),
@@ -50,7 +51,7 @@ def test_decode_attention_sweep(dtype, B, H, KV, W, hd, nvalid):
     kc = rand(ks[1], (B, KV, W, hd), dtype)
     vc = rand(ks[2], (B, KV, W, hd), dtype)
     valid = (jnp.arange(W) < nvalid).astype(jnp.int32)
-    a = ops.decode_attention(q, kc, vc, valid)
+    a = ops.decode_attention(q, kc, vc, valid, interpret=True)
     b = R.decode_attention_ref(q, kc, vc, valid)
     np.testing.assert_allclose(np.asarray(a, np.float32),
                                np.asarray(b, np.float32),
@@ -68,13 +69,14 @@ def test_decode_ring_mask_matches_linear():
 
 
 @pytest.mark.parametrize("B,S,D,block", [(1, 16, 64, 64), (2, 33, 128, 64),
-                                         (3, 8, 96, 32)])
+                                         (3, 8, 96, 32),
+                                         (2, 512, 128, 64)])  # 2 time blocks
 def test_rglru_sweep(B, S, D, block):
     ks = jax.random.split(KEY, 3)
     a = jax.random.uniform(ks[0], (B, S, D), minval=0.7, maxval=0.999)
     b = jax.random.normal(ks[1], (B, S, D)) * 0.1
     h0 = jax.random.normal(ks[2], (B, D))
-    y1, h1 = ops.rglru_scan(a, b, h0)
+    y1, h1 = ops.rglru_scan(a, b, h0, interpret=True)
     y2, h2 = R.rglru_scan_ref(a, b, h0)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=1e-5)
@@ -90,7 +92,7 @@ def test_wkv6_sweep(B, H, S, hd):
     w = jax.random.uniform(ks[3], (B, H, S, hd), minval=0.8, maxval=0.999)
     u = jax.random.uniform(ks[4], (H, hd))
     s0 = jax.random.normal(ks[5], (B, H, hd, hd)) * 0.1
-    y1, s1 = ops.wkv6(r, k, v, w, u, s0)
+    y1, s1 = ops.wkv6(r, k, v, w, u, s0, interpret=True)
     y2, s2 = R.wkv6_ref(r, k, v, w, u, s0)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                atol=1e-4, rtol=1e-4)
@@ -109,7 +111,7 @@ def test_kernels_match_model_semantics():
     model_out = attention(q, k, v)
     kern_out = ops.flash_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3))
+        v.transpose(0, 2, 1, 3), interpret=True)
     np.testing.assert_allclose(np.asarray(model_out),
                                np.asarray(kern_out.transpose(0, 2, 1, 3)),
                                atol=2e-5)

@@ -1,5 +1,8 @@
 """Real-execution mode: actual JAX model with KV-prefix reuse — the cached
 path must be numerically identical to recomputing the full prompt."""
+import importlib.util
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +11,8 @@ import pytest
 from repro.configs import get_config
 from repro.core.kvstore import KVStore
 from repro.core.policies import POLICIES
+from repro.launch import serve
+from repro.launch.serve import SessionTraffic, compare_hit_prefill, run_real
 from repro.models.transformer import init_params, prefill
 from repro.serving.realexec import RealExecutionEngine
 
@@ -77,3 +82,77 @@ def test_store_tracks_real_payload_bytes():
     assert e.payload is not None
     assert e.num_tokens == 12 + 0  # prompt cached (decode tokens excluded
     # from the key count is implementation detail: prompt_tokens inserted)
+
+
+TINY_TRAFFIC = SessionTraffic(prompt_len=24, followup_len=8, control_len=12,
+                              num_new=3)
+
+
+def _load_chip_smoke():
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_run_real_sized_store_hits_on_turn2():
+    """A store sized from the config and the traffic holds every prompt:
+    both turn 2s reuse turn 1's prompt, the control misses, nothing is
+    evicted, and the measured pass repeats the warm-up without compiling."""
+    cfg = get_config("yi-6b").reduced(num_layers=2, d_model=128)
+    t = TINY_TRAFFIC
+    run = run_real(cfg, t, dtype=jnp.float32, max_len=64,
+                   store_bytes=t.store_bytes(cfg))
+    res = run.results
+    for s in serve.SESSIONS:
+        assert res[f"{s}/turn1"].reused_tokens == 0
+        assert res[f"{s}/turn2"].reused_tokens == t.prompt_len
+        assert res[f"{s}/turn2"].prefill_tokens_computed == \
+            t.num_new + t.followup_len
+    assert res["control"].reused_tokens == 0
+    assert run.engine.store.stats.evictions == 0
+    assert run.window.programs == 0
+    assert {n: r.tokens for n, r in res.items()} == \
+        {n: r.tokens for n, r in run.warmup.items()}
+    cmp = compare_hit_prefill(run.engine, run.prompts["s0/turn2"],
+                              t.prompt_len)
+    assert cmp["finite"] and cmp["argmax_agree"] == 1.0
+    assert cmp["rel_l2"] < 1e-5
+
+
+def test_chip_smoke_checks_pass_on_reduced_model():
+    """The smoke's own checks, on a bf16 model far smaller than the chip's,
+    at the tolerance the chip run uses."""
+    cs = _load_chip_smoke()
+    cfg = get_config("llama3-8b").reduced(num_layers=2, d_model=128)
+    assert cs.smoke(cfg, TINY_TRAFFIC, dtype=jnp.bfloat16, max_len=64,
+                    rel_l2_tol=cs.REL_L2_TOL) == {}
+
+
+def test_chip_smoke_refuses_a_host_without_tpu(capsys):
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("a TPU is attached: the smoke would run")
+    assert _load_chip_smoke().main() == 1
+    assert "found platform 'cpu'" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, where set, is left to JAX; otherwise the
+    cache is the checkout's fixed .jax_cache."""
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = serve.use_compile_cache()
+        now = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if env_dir is None:
+        assert got == now == str(serve.CHECKOUT / ".jax_cache")
+        assert (serve.CHECKOUT / "chip_smoke.py").exists()
+    else:
+        assert got == env_dir and now == before
