@@ -195,6 +195,21 @@ def run_simulation(args):
     return res
 
 
+def write_trace(rec, path: str) -> str:
+    """Write ``rec`` as JSONL to ``path`` and as a Chrome ``trace_event``
+    file beside it (``out.jsonl`` -> ``out.trace.json``); returns the
+    latter's path."""
+    rec.write_jsonl(path)
+    chrome = path
+    for suf in (".jsonl", ".json"):
+        if chrome.endswith(suf):
+            chrome = chrome[:-len(suf)]
+            break
+    chrome += ".trace.json"
+    rec.write_chrome(chrome)
+    return chrome
+
+
 def write_observability(args, ctl, res):
     """Flight-recorder exports after a simulated day: the JSONL span
     trace plus its Chrome ``trace_event`` twin (``--trace out.jsonl`` →
@@ -202,14 +217,7 @@ def write_observability(args, ctl, res):
     (``--metrics out.prom``), and the final hour's solver candidate
     table (``--explain``)."""
     if args.trace:
-        ctl.trace.write_jsonl(args.trace)
-        chrome = args.trace
-        for suf in (".jsonl", ".json"):
-            if chrome.endswith(suf):
-                chrome = chrome[:-len(suf)]
-                break
-        chrome += ".trace.json"
-        ctl.trace.write_chrome(chrome)
+        chrome = write_trace(ctl.trace, args.trace)
         s = ctl.trace.summary()
         print(f"  trace:          {ctl.trace.n} spans, "
               f"{len(ctl.trace.events)} events -> {args.trace} "
@@ -341,14 +349,16 @@ def _serve(eng, traffic: SessionTraffic, seed: int):
 
 
 def run_real(cfg, traffic: SessionTraffic, *, dtype, max_len: int,
-             store_bytes: float, seed: int = 0) -> RealRun:
+             store_bytes: float, seed: int = 0, recorder=None) -> RealRun:
     """Serve ``traffic`` with a random-weight ``cfg`` behind a GreenCache
     ``KVStore`` of ``store_bytes``, through ``RealExecutionEngine``.
 
     The traffic runs twice on the same engine, each time on an empty
     store: a warm-up pass that compiles every shape, then the measured
     pass, whose results are returned. The same seed gives both passes the
-    same prompts, so the measured pass should compile nothing."""
+    same prompts, so the measured pass should compile nothing. A
+    ``recorder`` (``repro.obs.TraceRecorder``) is attached to the engine
+    for the measured pass."""
     import jax
     from repro.core.kvstore import KVStore
     from repro.core.policies import POLICIES
@@ -368,6 +378,7 @@ def run_real(cfg, traffic: SessionTraffic, *, dtype, max_len: int,
         _, warmup = _serve(eng, traffic, seed)
     setup_s = time.perf_counter() - t0
     eng.store = store()
+    eng.recorder = recorder
     with CompileClock() as window:
         prompts, results = _serve(eng, traffic, seed)
     for name, r in results.items():
@@ -403,16 +414,24 @@ def compare_hit_prefill(eng, prompt, prefix_len: int) -> dict:
 
 
 def run_real_demo(args):
-    """``--real``: a reduced model on the CPU; turn 2 must hit."""
+    """``--real``: a reduced model on the CPU; turn 2 must hit. With
+    ``--trace OUT.jsonl`` the engine's recorder is attached to the measured
+    pass and exported as for a simulated day."""
     import jax.numpy as jnp
     from repro.configs import get_config
+    from repro.obs import TraceRecorder
 
     use_compile_cache()
     cfg = get_config(args.arch).reduced(num_layers=2, d_model=128)
     traffic = SessionTraffic(prompt_len=24, followup_len=8, control_len=12,
                              num_new=4)
+    rec = TraceRecorder() if args.trace else None
     run = run_real(cfg, traffic, dtype=jnp.float32, max_len=128,
-                   store_bytes=traffic.store_bytes(cfg))
+                   store_bytes=traffic.store_bytes(cfg), recorder=rec)
+    if rec is not None:
+        chrome = write_trace(rec, args.trace)
+        print(f"engine trace: {rec.n} requests, {len(rec.events)} events "
+              f"-> {args.trace} (+ {chrome})")
     r2 = run.results["s0/turn2"]
     if r2.reused_tokens != traffic.prompt_len:
         raise SystemExit(f"expected turn 2 to reuse {traffic.prompt_len} "

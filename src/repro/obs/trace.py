@@ -160,6 +160,33 @@ class TraceRecorder:
         c["carbon_g"][s] = (energy_j_per_req / 3.6e6) * ci_g_per_kwh
         self.n += k
 
+    def record_request(self, *, rid: int, arrival_s: float, hit_kind: str,
+                       matched_tokens: int, prompt_tokens: int,
+                       output_tokens: int, prefill_s: float,
+                       decode_s: float):
+        """Record one request served by an engine that sees requests one
+        at a time (``RealExecutionEngine``), over the same columns as
+        ``record_window``. ``hit_kind`` is a ``HIT_KIND_CODES`` name. The
+        other columns stay 0 (``hit_tier`` -1, labels empty): such an
+        engine knows neither the request's queueing nor its latency as the
+        client sees it nor its energy, and restores a hit's KV inside the
+        prefill (``kv_load_s``)."""
+        self._grow(1)
+        i, c = self.n, self._cols     # rows past n are still zero
+        c["region"][i] = self.regions("")
+        c["tier"][i] = self.tiers("")
+        c["tenant"][i] = self.tenants("")
+        c["hit_tier"][i] = -1
+        c["rid"][i] = rid
+        c["arrival_s"][i] = arrival_s
+        c["hit_kind"][i] = HIT_KIND_CODES[hit_kind]
+        c["matched_tokens"][i] = matched_tokens
+        c["prompt_tokens"][i] = prompt_tokens
+        c["output_tokens"][i] = output_tokens
+        c["prefill_s"][i] = prefill_s
+        c["decode_s"][i] = decode_s
+        self.n += 1
+
     def record_event(self, kind: str, ts: float, *, region: str = "",
                      **attrs):
         """Control-plane event (transition, failure, preempt, WAN

@@ -23,9 +23,35 @@ Griffin/RG-LRU) use state-snapshot caching instead — the fixed-size
 recurrent state after the prefix is stored, since their "KV" does not grow
 with context. Drive it via ``python -m repro.launch.serve --real
 --arch yi-6b`` or the quickstart example.
+
+The jitted programs are named, so a profiler trace shows them as
+``jit_prefill_miss``, ``jit_prefill_hit`` and ``jit_decode_step`` whatever
+the shape. With a ``repro.obs.TraceRecorder`` attached as ``recorder``
+(``None`` by default), ``generate`` also writes what it does onto the
+profiler's clock and into the recorder:
+
+* ``jax.profiler.TraceAnnotation`` spans, each carrying the request's
+  ``rid`` (a per-engine counter) and ``context_key`` as metadata:
+  ``engine.generate`` (the whole call) holds ``engine.lookup`` (the store
+  lookup and the payload hand-over), ``engine.prefill`` (dispatch through
+  ``block_until_ready``, with ``suffix_len`` and ``prefix_len``; on a hit
+  the restore happens inside the prefill program, which reads the stored
+  ring, so there is no restore span), ``engine.insert``, and
+  ``engine.decode`` (the interval of ``decode_time_s``). Inside
+  ``engine.decode`` each step is ``engine.decode_step`` (the decode
+  program's dispatch with its input uploads) and ``engine.sample`` (the
+  logits slice, the argmax and the host read of the token); the first
+  ``engine.sample`` reads the prefill's token.
+* one request row (``TraceRecorder.record_request``);
+* a ``store_bytes`` event: the device bytes of every live entry's payload
+  against the bytes the store charges for them.
+
+With no recorder none of this happens: no span object is made and
+nothing is written.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass
 from typing import List, Optional
@@ -35,7 +61,25 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.kvstore import KVStore
-from repro.models.transformer import (decode_step, init_cache, prefill)
+from repro.models import transformer as tfm
+from repro.models.transformer import init_cache
+
+_OFF = contextlib.nullcontext()
+
+
+def _span(meta: Optional[dict], name: str, **extra):
+    """The profiler span ``name`` with the request's metadata, or a shared
+    no-op when the engine records nothing (``meta`` is None)."""
+    if meta is None:
+        return _OFF
+    return jax.profiler.TraceAnnotation(name, **meta, **extra)
+
+
+def payload_bytes(store: KVStore) -> int:
+    """Device bytes held by the payloads of every live entry of ``store``."""
+    return sum(getattr(leaf, "nbytes", 0)
+               for e in store.entries.values() if e.payload is not None
+               for leaf in jax.tree_util.tree_leaves(e.payload))
 
 
 @dataclass
@@ -56,8 +100,12 @@ class RealExecutionEngine:
         self.max_len = max_len
         self.dtype = dtype
         self._prefill_cached = {}
-        self._decode = jax.jit(
-            lambda p, c, t, pos: decode_step(p, cfg, c, t, pos))
+        self.recorder = None    # repro.obs.TraceRecorder, when attached
+        self._rid = 0
+
+        def decode_step(p, c, t, pos):
+            return tfm.decode_step(p, cfg, c, t, pos)
+        self._decode = jax.jit(decode_step)
 
     # ------------------------------------------------------------------ #
     def prefill(self, tokens: jnp.ndarray, prefix_cache=None,
@@ -67,14 +115,16 @@ class RealExecutionEngine:
         (logits (1, S, V), cache)."""
         key = (tokens.shape[1], prefix_len)
         if key not in self._prefill_cached:
-            cfgl = self.cfg
-            if prefix_len:
-                fn = lambda p, b, pc: prefill(p, cfgl, b, self.max_len,
-                                              prefix_cache=pc,
-                                              prefix_len=prefix_len)
-            else:
-                fn = lambda p, b: prefill(p, cfgl, b, self.max_len)
-            self._prefill_cached[key] = jax.jit(fn)
+            cfg, max_len = self.cfg, self.max_len
+
+            def prefill_hit(p, b, pc):
+                return tfm.prefill(p, cfg, b, max_len, prefix_cache=pc,
+                                   prefix_len=prefix_len)
+
+            def prefill_miss(p, b):
+                return tfm.prefill(p, cfg, b, max_len)
+            self._prefill_cached[key] = jax.jit(
+                prefill_hit if prefix_len else prefill_miss)
         fn = self._prefill_cached[key]
         batch = {"tokens": tokens}
         if prefix_len:
@@ -86,60 +136,97 @@ class RealExecutionEngine:
                  num_new: int = 8, now: Optional[float] = None
                  ) -> GenerationResult:
         """Serve one request: reuse the cached prefix KV for ``context_key``
-        if present, prefill the suffix, then greedy-decode ``num_new``."""
+        if present, prefill the suffix, then greedy-decode ``num_new``.
+        With a recorder attached, also write the spans, the request row
+        and the ``store_bytes`` event of the module docstring."""
         now = time.time() if now is None else now
-        recurrent = self.cfg.family in ("ssm", "hybrid")
-        entry = self.store.lookup(context_key, len(prompt_tokens), now)
-        prefix_len = 0
-        prefix_cache = None
-        if entry is not None and entry.payload is not None:
-            plen, pcache = entry.payload
-            if plen <= len(prompt_tokens):
-                prefix_len, prefix_cache = plen, pcache
+        rec = self.recorder
+        if rec is None:
+            return self._generate(context_key, prompt_tokens, num_new, now,
+                                  None)
+        self._rid += 1
+        meta = {"rid": self._rid, "context_key": context_key}
+        with _span(meta, "engine.generate"):
+            g = self._generate(context_key, prompt_tokens, num_new, now,
+                               meta)
+        rec.record_request(
+            rid=self._rid, arrival_s=now,
+            hit_kind="hit" if g.reused_tokens else "miss",
+            matched_tokens=g.reused_tokens,
+            prompt_tokens=len(prompt_tokens), output_tokens=len(g.tokens),
+            prefill_s=g.prefill_time_s, decode_s=g.decode_time_s)
+        rec.record_event("store_bytes", now,
+                         payload_bytes=payload_bytes(self.store),
+                         charged_bytes=self.store.used_bytes,
+                         entries=len(self.store.entries))
+        return g
 
-        t0 = time.perf_counter()
-        if recurrent:
-            # state-snapshot caching: restore state, run the suffix through
-            # decode steps (prefill from state not implemented for brevity —
-            # suffix processed token by token, still skipping prefix compute)
-            if prefix_cache is not None:
-                cache = prefix_cache
+    def _generate(self, context_key, prompt_tokens, num_new, now, meta
+                  ) -> GenerationResult:
+        recurrent = self.cfg.family in ("ssm", "hybrid")
+        with _span(meta, "engine.lookup"):
+            entry = self.store.lookup(context_key, len(prompt_tokens), now)
+            prefix_len = 0
+            prefix_cache = None
+            if entry is not None and entry.payload is not None:
+                plen, pcache = entry.payload
+                if plen <= len(prompt_tokens):
+                    prefix_len, prefix_cache = plen, pcache
+
+        with _span(meta, "engine.prefill",
+                   suffix_len=len(prompt_tokens) - prefix_len,
+                   prefix_len=prefix_len):
+            t0 = time.perf_counter()
+            if recurrent:
+                # state-snapshot caching: restore state, run the suffix
+                # through decode steps (prefill from state not implemented
+                # for brevity — suffix processed token by token, still
+                # skipping prefix compute)
+                if prefix_cache is not None:
+                    cache = prefix_cache
+                else:
+                    cache = init_cache(self.cfg, 1, self.max_len, self.dtype)
+                    prefix_len = 0
+                logits = None
+                pos = prefix_len
+                for t in prompt_tokens[prefix_len:]:
+                    logits, cache = self._decode(
+                        self.params, cache, jnp.array([[t]], jnp.int32),
+                        jnp.asarray(pos))
+                    pos += 1
             else:
-                cache = init_cache(self.cfg, 1, self.max_len, self.dtype)
-                prefix_len = 0
-            logits = None
-            pos = prefix_len
-            for t in prompt_tokens[prefix_len:]:
-                logits, cache = self._decode(
-                    self.params, cache, jnp.array([[t]], jnp.int32),
-                    jnp.asarray(pos))
-                pos += 1
-        else:
-            suffix = jnp.asarray(prompt_tokens[prefix_len:],
-                                 jnp.int32)[None]
-            logits, cache = self.prefill(suffix, prefix_cache, prefix_len)
-            pos = len(prompt_tokens)
-        logits.block_until_ready()      # time the device, not the dispatch
-        t_prefill = time.perf_counter() - t0
+                suffix = jnp.asarray(prompt_tokens[prefix_len:],
+                                     jnp.int32)[None]
+                logits, cache = self.prefill(suffix, prefix_cache, prefix_len)
+                pos = len(prompt_tokens)
+            logits.block_until_ready()      # time the device, not the dispatch
+            t_prefill = time.perf_counter() - t0
 
         # store the full-prompt cache back (extends the prefix entry)
-        self.store.insert(context_key, len(prompt_tokens), now,
-                          payload=(len(prompt_tokens), cache))
+        with _span(meta, "engine.insert"):
+            self.store.insert(context_key, len(prompt_tokens), now,
+                              payload=(len(prompt_tokens), cache))
 
         # greedy decode
-        t1 = time.perf_counter()
-        out = []
-        tok = int(jnp.argmax(logits[0, -1, :self.cfg.vocab_size]))
-        for _ in range(num_new):
-            out.append(tok)
-            logits, cache = self._decode(
-                self.params, cache, jnp.array([[tok]], jnp.int32),
-                jnp.asarray(pos))
-            pos += 1
-            tok = int(jnp.argmax(logits[0, -1, :self.cfg.vocab_size]))
+        V = self.cfg.vocab_size
+        with _span(meta, "engine.decode"):
+            t1 = time.perf_counter()
+            out = []
+            with _span(meta, "engine.sample"):
+                tok = int(jnp.argmax(logits[0, -1, :V]))
+            for _ in range(num_new):
+                out.append(tok)
+                with _span(meta, "engine.decode_step"):
+                    logits, cache = self._decode(
+                        self.params, cache, jnp.array([[tok]], jnp.int32),
+                        jnp.asarray(pos))
+                pos += 1
+                with _span(meta, "engine.sample"):
+                    tok = int(jnp.argmax(logits[0, -1, :V]))
+            t_decode = time.perf_counter() - t1
         return GenerationResult(
             tokens=out,
             prefill_tokens_computed=len(prompt_tokens) - prefix_len,
             reused_tokens=prefix_len,
             prefill_time_s=t_prefill,
-            decode_time_s=time.perf_counter() - t1)
+            decode_time_s=t_decode)

@@ -180,6 +180,25 @@ def test_recorder_jsonl_and_chrome_roundtrip(tmp_path):
     assert all(v == pytest.approx(3.6e6) for v in by_rid.values())
 
 
+def test_record_request_fills_one_row_beside_windows():
+    rec = TraceRecorder(capacity=16)
+    _record_some(rec, k=16)
+    rec.record_request(rid=7, arrival_s=2.5, hit_kind="hit",
+                       matched_tokens=96, prompt_tokens=128,
+                       output_tokens=4, prefill_s=0.01, decode_s=0.04)
+    assert rec.n == 17 and rec.capacity >= 17
+    row = list(rec.rows())[-1]
+    assert row["rid"] == 7 and row["arrival_s"] == 2.5
+    assert row["hit_kind"] == "hit" and row["hit_tier"] == -1
+    assert (row["matched_tokens"], row["prompt_tokens"],
+            row["output_tokens"]) == (96, 128, 4)
+    assert (row["prefill_s"], row["decode_s"]) == (0.01, 0.04)
+    # what the engine cannot know stays 0
+    assert all(row[k] == 0 for k in ("queue_s", "kv_load_s", "ttft_s",
+                                     "tpot_s", "energy_j", "carbon_g"))
+    assert row["tier"] == "" and row["tenant"] == ""
+
+
 # ------------------------------------------------------------------ #
 # exact_partition / CarbonLedger
 # ------------------------------------------------------------------ #

@@ -156,3 +156,158 @@ def test_compile_cache_dir(monkeypatch, env_dir):
         assert (serve.CHECKOUT / "chip_smoke.py").exists()
     else:
         assert got == env_dir and now == before
+
+
+def _serve_two_turns(eng, cfg, seed=3):
+    """Two turns of one session and a control request; turn 2 hits."""
+    rng = np.random.default_rng(seed)
+    draw = lambda n: [int(t) for t in rng.integers(0, cfg.vocab_size, n)]
+    ctx = draw(20)
+    out = [eng.generate("s", ctx, num_new=3, now=1.0)]
+    out.append(eng.generate("s", ctx + out[0].tokens + draw(5), num_new=3,
+                            now=2.0))
+    out.append(eng.generate("c", draw(12), num_new=2, now=3.0))
+    return out
+
+
+def test_recorder_leaves_generation_unchanged():
+    """The bit-identity contract: the same tokens and counts with a
+    recorder attached and without one."""
+    from repro.obs import TraceRecorder
+    cfg, _, bare = make_engine("yi-6b")
+    _, _, traced = make_engine("yi-6b")
+    assert bare.recorder is None
+    traced.recorder = TraceRecorder()
+    for a, b in zip(_serve_two_turns(bare, cfg),
+                    _serve_two_turns(traced, cfg)):
+        assert (a.tokens, a.reused_tokens, a.prefill_tokens_computed) == \
+            (b.tokens, b.reused_tokens, b.prefill_tokens_computed)
+    assert len(bare.store.entries) == len(traced.store.entries) == 2
+
+
+def test_recorder_writes_one_row_per_request():
+    from repro.obs import TraceRecorder
+    cfg, _, eng = make_engine("yi-6b")
+    rec = eng.recorder = TraceRecorder()
+    res = _serve_two_turns(eng, cfg)
+    rows = list(rec.rows())
+    assert [r["rid"] for r in rows] == [1, 2, 3]
+    assert [r["arrival_s"] for r in rows] == [1.0, 2.0, 3.0]
+    assert [r["hit_kind"] for r in rows] == ["miss", "hit", "miss"]
+    assert [r["matched_tokens"] for r in rows] == [0, 20, 0]
+    assert [r["prompt_tokens"] for r in rows] == [20, 28, 12]
+    assert [r["output_tokens"] for r in rows] == [3, 3, 2]
+    for r, g in zip(rows, res):
+        assert r["prefill_s"] == g.prefill_time_s
+        assert r["decode_s"] == g.decode_time_s
+        assert r["kv_load_s"] == r["queue_s"] == r["ttft_s"] == 0
+
+
+def test_store_bytes_event_counts_whole_rings():
+    """Each payload is a whole ``max_len`` ring of keys and values, while
+    the store charges the prompt's tokens."""
+    from repro.obs import TraceRecorder
+    cfg, _, eng = make_engine("yi-6b")
+    rec = eng.recorder = TraceRecorder()
+    _serve_two_turns(eng, cfg)
+    ring = 2 * cfg.num_layers * eng.max_len * cfg.num_kv_heads \
+        * cfg.head_dim * np.dtype(np.float32).itemsize
+    evs = [e for e in rec.events if e["kind"] == "store_bytes"]
+    assert [e["entries"] for e in evs] == [1, 1, 2]
+    assert [e["payload_bytes"] for e in evs] == [ring, ring, 2 * ring]
+    assert [e["ts"] for e in evs] == [1.0, 2.0, 3.0]
+    assert evs[-1]["charged_bytes"] == eng.store.used_bytes == \
+        (28 + 12) * cfg.kv_bytes_per_token
+
+
+def test_programs_are_named():
+    """The programs a trace shows are named by kind, not by shape."""
+    cfg, params, eng = make_engine("yi-6b")
+    rng = np.random.default_rng(4)
+    ctx = [int(t) for t in rng.integers(0, cfg.vocab_size, 16)]
+    eng.generate("a", ctx, num_new=1)
+    eng.generate("a", ctx + [1, 2, 3], num_new=1)
+    toks = jnp.zeros((1, 16), jnp.int32)
+    miss = eng._prefill_cached[(16, 0)].lower(params, {"tokens": toks})
+    _, cache = eng.prefill(toks)
+    hit = eng._prefill_cached[(3, 16)].lower(
+        params, {"tokens": toks[:, :3]}, cache)
+    step = eng._decode.lower(params, cache, jnp.zeros((1, 1), jnp.int32),
+                             jnp.asarray(16))
+    for lowered, name in ((miss, "jit_prefill_miss"),
+                          (hit, "jit_prefill_hit"),
+                          (step, "jit_decode_step")):
+        assert f"module @{name} " in lowered.as_text()
+
+
+def test_profiler_trace_holds_engine_spans_per_request(tmp_path):
+    """Two requests under the CPU profiler: every ``engine.*`` span carries
+    its request's ``rid`` and ``context_key``, and the decode steps sit
+    inside ``engine.decode``."""
+    from jax.profiler import ProfileData
+    from repro.obs import TraceRecorder
+    cfg, _, eng = make_engine("yi-6b")
+    eng.recorder = TraceRecorder()
+    rng = np.random.default_rng(5)
+    ctx = [int(t) for t in rng.integers(0, cfg.vocab_size, 16)]
+    eng.generate("a", ctx, num_new=2)         # compile outside the trace
+    eng.generate("a", ctx + [7, 8], num_new=2)
+    eng.store = KVStore(64e6, POLICIES["lcs"], cfg.kv_bytes_per_token)
+    jax.profiler.start_trace(str(tmp_path))
+    eng.generate("a", ctx, num_new=2)
+    eng.generate("a", ctx + [7, 8], num_new=2)
+    jax.profiler.stop_trace()
+    path, = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    spans = [(ev.name, dict(ev.stats), ev.start_ns, ev.duration_ns)
+             for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("engine.")]
+    by_rid = {}
+    for name, stats, _, _ in spans:
+        assert stats["context_key"] == "a"
+        by_rid.setdefault(stats["rid"], []).append(name)
+    assert sorted(by_rid) == [3, 4]
+    for names in by_rid.values():
+        assert names.count("engine.generate") == 1
+        for one in ("engine.lookup", "engine.prefill", "engine.insert",
+                    "engine.decode"):
+            assert names.count(one) == 1
+        assert names.count("engine.decode_step") == 2
+        assert names.count("engine.sample") == 3
+    prefill = [s for n, s, _, _ in spans if n == "engine.prefill"]
+    assert [(s["suffix_len"], s["prefix_len"]) for s in prefill] == \
+        [(16, 0), (2, 16)]
+    decode = [(t, t + d) for n, _, t, d in spans if n == "engine.decode"]
+    for n, _, t, d in spans:
+        if n == "engine.decode_step":
+            assert any(a <= t and t + d <= b for a, b in decode)
+
+
+def test_serve_real_writes_the_engine_trace(tmp_path, capsys):
+    """``serve --real --trace`` attaches the recorder to the measured pass
+    and writes the JSONL and Chrome exports."""
+    import json
+    out = tmp_path / "real.jsonl"
+    run = serve.main(["--real", "--arch", "yi-6b", "--trace", str(out)])
+    rows = [json.loads(x) for x in out.read_text().splitlines()]
+    reqs = [r for r in rows if r["type"] == "request"]
+    assert len(reqs) == len(run.results) == 5
+    assert sum(r["hit_kind"] == "hit" for r in reqs) == 2
+    evs = [r for r in rows if r["type"] == "event"]
+    assert len(evs) == 5 and all(e["kind"] == "store_bytes" for e in evs)
+    assert all(e["payload_bytes"] > e["charged_bytes"] > 0 for e in evs)
+    chrome = json.loads((tmp_path / "real.trace.json").read_text())
+    assert {e["name"] for e in chrome["traceEvents"]
+            if e["ph"] == "X"} == {"prefill", "decode"}
+    assert "engine trace" in capsys.readouterr().out
+
+
+def test_no_recorder_makes_no_span(monkeypatch):
+    """Detached (the default), ``generate`` makes no profiler span."""
+    cfg, _, eng = make_engine("yi-6b")
+
+    def refuse(*a, **k):
+        raise AssertionError("a span was made with no recorder attached")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    res = _serve_two_turns(eng, cfg)
+    assert [g.reused_tokens for g in res] == [0, 20, 0]
