@@ -13,7 +13,8 @@ from repro.core.kvstore import KVStore
 from repro.core.policies import POLICIES
 from repro.launch import serve
 from repro.launch.serve import SessionTraffic, compare_hit_prefill, run_real
-from repro.models.transformer import init_params, prefill
+from repro.models import transformer as tfm
+from repro.models.transformer import init_cache, init_params, prefill
 from repro.serving.realexec import RealExecutionEngine
 
 # real JAX execution / end-to-end simulation: excluded from the fast CI
@@ -72,6 +73,141 @@ def test_multi_turn_reuse_identical_output(arch):
     assert rc.tokens == r2.tokens
 
 
+def _load(name, rel_path):
+    """The checkout's file ``rel_path`` imported as module ``name``."""
+    path = Path(__file__).resolve().parents[1] / rel_path
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def make_window_engine():
+    """The benchmark's tiny dense GQA configuration with a 32-token
+    sliding window, in bf16 as the benchmark's configurations run."""
+    conf = _load("bench_conftest", "tests/bench/conftest.py").TINY_CONF
+    from bench.configs import model_config
+    cfg = model_config(dict(conf, dims=dict(conf["dims"], window=32)))
+    params = init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    store = KVStore(64e6, POLICIES["lcs"], cfg.kv_bytes_per_token)
+    return cfg, params, RealExecutionEngine(cfg, params, store, max_len=128,
+                                            dtype=jnp.bfloat16)
+
+
+def _host_greedy(eng, prompt, num_new):
+    """Greedy tokens from a plain loop: ``tfm.decode_step`` in a program,
+    the argmax on the host, the next token and position uploaded."""
+    cfg, params = eng.cfg, eng.params
+    step = jax.jit(lambda p, c, t, pos: tfm.decode_step(p, cfg, c, t, pos))
+    if cfg.family in ("ssm", "hybrid"):
+        cache = init_cache(cfg, 1, eng.max_len, eng.dtype)
+        for pos, t in enumerate(prompt):
+            logits, cache = step(params, cache, jnp.array([[t]], jnp.int32),
+                                 jnp.asarray(pos))
+    else:
+        logits, cache = jax.jit(
+            lambda p, b: prefill(p, cfg, b, eng.max_len))(
+                params, {"tokens": jnp.asarray(prompt, jnp.int32)[None]})
+    V = cfg.vocab_size
+    out = [int(jnp.argmax(logits[0, -1, :V]))]
+    for pos in range(len(prompt), len(prompt) + num_new - 1):
+        logits, cache = step(params, cache, jnp.array([[out[-1]]], jnp.int32),
+                             jnp.asarray(pos))
+        out.append(int(jnp.argmax(logits[0, -1, :V])))
+    return out
+
+
+@pytest.mark.parametrize("arch", ["yi-6b", "rwkv6-1.6b", "recurrentgemma-2b",
+                                  "window32"])
+def test_device_side_greedy_matches_host_argmax(arch):
+    """The decode program's own argmax gives the tokens of a loop that
+    reads every step's logits on the host; the window case decodes across
+    its 32-token window."""
+    cfg, _, eng = make_window_engine() if arch == "window32" else \
+        make_engine(arch)
+    rng = np.random.default_rng(6)
+    ctx = [int(t) for t in rng.integers(0, cfg.vocab_size, 28)]
+    assert eng.generate("a", ctx, num_new=6).tokens == \
+        _host_greedy(eng, ctx, 6)
+
+
+@pytest.mark.parametrize("arch", ["yi-6b", "rwkv6-1.6b"])
+def test_decode_program_compiles_once(arch):
+    """A step fed from the host (the first) and one fed by the step before
+    it run one compiled program, so a warm-up at ``num_new=1`` covers every
+    later request length."""
+    cfg, _, eng = make_engine(arch)
+    rng = np.random.default_rng(7)
+    ctx = [int(t) for t in rng.integers(0, cfg.vocab_size, 12)]
+    eng.generate("a", ctx, num_new=1)
+    assert eng._decode._cache_size() == 1
+    eng.generate("b", ctx[:9], num_new=5)
+    assert eng._decode._cache_size() == 1
+
+
+def test_steps_chain_on_device_outputs():
+    """Each step takes the very arrays the step before it returned, so no
+    host value is uploaded between steps; ``generate`` returns after the
+    last step has finished."""
+    cfg, _, eng = make_engine("yi-6b")
+    inner, calls = eng._decode, []
+
+    def spy(*args):
+        out = inner(*args)
+        calls.append((args, out))
+        return out
+    eng._decode = spy
+    rng = np.random.default_rng(8)
+    ctx = [int(t) for t in rng.integers(0, cfg.vocab_size, 12)]
+    g = eng.generate("a", ctx, num_new=5)
+    assert len(calls) == 5
+    assert int(calls[0][0][3]) == len(ctx)
+    for (_, ((tok, pos), cache)), (args, _) in zip(calls, calls[1:]):
+        assert args[1] is cache and args[2] is tok and args[3] is pos
+    assert g.tokens[1:] == [int(out[0][0][0, 0]) for _, out in calls[:-1]]
+    assert all(x.is_ready() for x in jax.tree_util.tree_leaves(calls[-1][1]))
+
+
+def test_decode_bounds_the_steps_in_flight():
+    """Before step k is dispatched the host waits for step k - 2 (each
+    queued step holds a cache of its own), and ``generate`` waits for the
+    last step before it returns."""
+    from repro.serving import realexec
+    cfg, _, eng = make_engine("yi-6b")
+    inner, log = eng._decode, []
+
+    class Token:        # a step's token that notes when the host waits
+        def __init__(self, a, k):
+            self.a, self.k = a, k
+
+        def block_until_ready(self):
+            log.append(("wait", self.k))
+            return self.a.block_until_ready()
+
+        def copy_to_host_async(self):
+            self.a.copy_to_host_async()
+
+        def item(self):
+            return self.a.item()
+
+    def step(p, c, t, pos):
+        k = sum(e == "step" for e, _ in log)
+        log.append(("step", k))
+        (tok, pos), c = inner(p, c, getattr(t, "a", t), pos)
+        return (Token(tok, k), pos), c
+    eng._decode = step
+    rng = np.random.default_rng(9)
+    ctx = [int(t) for t in rng.integers(0, cfg.vocab_size, 12)]
+    g = eng.generate("a", ctx, num_new=6)
+    n = realexec._IN_FLIGHT
+    want = []
+    for k in range(6):
+        want += [("wait", k - n)] if k >= n else []
+        want.append(("step", k))
+    assert log == want + [("wait", 5)]
+    assert g.tokens == _host_greedy(eng, ctx, 6)
+
+
 def test_store_tracks_real_payload_bytes():
     cfg, params, eng = make_engine("yi-6b")
     rng = np.random.default_rng(2)
@@ -89,11 +225,7 @@ TINY_TRAFFIC = SessionTraffic(prompt_len=24, followup_len=8, control_len=12,
 
 
 def _load_chip_smoke():
-    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
-    spec = importlib.util.spec_from_file_location("chip_smoke", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _load("chip_smoke", "chip_smoke.py")
 
 
 def test_run_real_sized_store_hits_on_turn2():
@@ -273,7 +405,8 @@ def test_profiler_trace_holds_engine_spans_per_request(tmp_path):
                     "engine.decode"):
             assert names.count(one) == 1
         assert names.count("engine.decode_step") == 2
-        assert names.count("engine.sample") == 3
+        # the prefill's token, then the decoded tokens in one read
+        assert names.count("engine.sample") == 2
     prefill = [s for n, s, _, _ in spans if n == "engine.prefill"]
     assert [(s["suffix_len"], s["prefix_len"]) for s in prefill] == \
         [(16, 0), (2, 16)]
