@@ -3,10 +3,11 @@ decides ``correct``.
 
 Once the window has closed and the program's state is freed, a sample of
 the requests it finished, drawn from the seed, goes through the plain
-float32 reference (``bench/references/<name>.py``): each prompt followed by
-the tokens served for it. The number compared is the widest gap by which a
-served token's reference logit lies below the reference's best logit at
-that position (0 where every served token is the reference's own choice).
+float32 reference of the configuration's kind
+(``bench/references/<kind>.py``): each prompt followed by the tokens served
+for it. The number compared is the widest gap by which a served token's
+reference logit lies below the reference's best logit at that position (0
+where every served token is the reference's own choice).
 The sample always holds the longest request, a request whose prefix was
 restored from the store (the hit path's prefill) and one that was
 prefilled whole (the miss path), where the window has them; every served
@@ -63,7 +64,8 @@ def _gaps(ref_logits, chosen):
 
 
 def reference_module(conf: dict):
-    return importlib.import_module(f"bench.references.{conf['reference']}")
+    return importlib.import_module(
+        f"bench.references.{conf['dims']['kind']}")
 
 
 def served_gap(conf: dict, seed: int, sample, prompt_of, pad_to: int = 0,

@@ -13,9 +13,9 @@ object: the cell's per-layer metrics as ``bench/run.py --trace 1`` reads
 them, the decode split per step (``decode_device_ms_per_step``,
 ``decode_overhead_ms_per_step``), ``store_payload_ratio``, the clock lag,
 ``programs`` and ``idle_in_serving``. ``--keep`` copies the trace file out;
-``--tiny`` serves a two-layer model for a second, which is how
-``tests/bench/data/engine_tiny.xplane.pb`` was recorded on one TPU v5e
-(then cut down by ``tools/slim_xplane.py``).
+``--tiny`` serves the two-layer model of ``bench/configs/tiny.json`` for a
+second, which is how ``tests/bench/data/engine_tiny.xplane.pb`` was
+recorded on one TPU v5e (then cut down by ``tools/slim_xplane.py``).
 """
 import time
 
@@ -33,11 +33,6 @@ if str(Path(__file__).resolve().parent) in sys.path:
     sys.path.remove(str(Path(__file__).resolve().parent))
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-TINY_CONF = {"name": "tiny", "source": "test", "reference": "dense_gqa",
-             "dims": {"layers": 2, "d_model": 128, "heads": 4, "kv_heads": 2,
-                      "head_dim": 32, "d_ff": 256, "vocab": 512,
-                      "window": None, "rope_theta": 5e6, "norm_eps": 1e-5},
-             "serve": {"dtype": "bfloat16", "max_len": 128}}
 TINY_MIX = {"name": "tiny", "turns": 2, "first_prompt_tokens": [32, 48],
             "append_tokens": 16, "output_tokens": 4, "gap_mean_s": 0.2,
             "rate_req_per_s": 4.0, "nominal_turn_s": 0.05, "history_s": 0,
@@ -58,15 +53,15 @@ def parse(argv):
 
 def traced_run(args, *, require_tpu: bool = True) -> dict:
     """One traced window of the cell with the engine's recorder attached,
-    at the cell's size, or at ``TINY_CONF`` and ``TINY_MIX`` under
-    ``--tiny``."""
+    at the cell's size, or at the ``tiny`` configuration and ``TINY_MIX``
+    under ``--tiny``."""
     from bench.compile_clock import use_compile_cache
     use_compile_cache()
     import jax
     import jax.numpy as jnp
 
-    from bench import cells, configs, schedule, trace_program, trace_reduce
-    from bench import weights
+    from bench import cells, configs, kinds, schedule, trace_program
+    from bench import trace_reduce
     from bench.driver import drive, serve_history
     from bench.peaks import peaks_for
     from bench.run import (SPANS, Run, _span, _spanned, find_devices,
@@ -78,12 +73,12 @@ def traced_run(args, *, require_tpu: bool = True) -> dict:
     dev = find_devices(cell.chips, require_tpu)
     peaks = peaks_for(dev["kind"] if require_tpu else "TPU v5 lite")
     if args.tiny:
-        conf, mix = TINY_CONF, TINY_MIX
+        conf, mix = configs.load("tiny"), TINY_MIX
     else:
         conf, mix = configs.load(cell.config), schedule.load_mix(cell.traffic)
     dims = conf["dims"]
     cfg = configs.model_config(conf)
-    params = jax.block_until_ready(weights.program_params(
+    params = jax.block_until_ready(kinds.module(dims["kind"]).program_params(
         args.seed, dims, jnp.dtype(conf["serve"]["dtype"])))
     eng = RealExecutionEngine(cfg, params, make_store(conf, mix, cfg),
                               max_len=conf["serve"]["max_len"],

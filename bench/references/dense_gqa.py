@@ -6,9 +6,9 @@ within the sliding window where there is one, a SwiGLU MLP
 
 It runs in float32 with ``jax.default_matmul_precision("highest")``, one
 layer at a time over every sequence, with attention in blocks of queries.
-Its weights are drawn anew from the seed (``bench.weights``), held in
-bfloat16 as served and widened to float32 one layer at a time, so it fits
-once the program's state is freed.
+Its weights are drawn anew from the seed by its kind
+(``bench/kinds/dense_gqa.py``), held in bfloat16 as served and widened to
+float32 one layer at a time, so it fits once the program's state is freed.
 
 ``quant="fp8"`` computes the same model one precision step below bfloat16,
 for the control, as an fp8 (e4m3) deployment would: weights scaled per
@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
+from bench.kinds import dense_gqa as kind
 
 NEG = -1e30
 
@@ -111,8 +111,8 @@ def logits_at(seed: int, dims: dict, sequences, positions, *, quant=None,
     next-token logits are wanted. Sequences are padded with token 0 to one
     length (at least ``pad_to``, a multiple of ``block``): causal attention
     keeps the padding out of every real position."""
-    params = weights.program_params(seed, dims)
-    stacks = weights.by_name(params)
+    params = kind.program_params(seed, dims)
+    stacks = kind.by_name(params)
     frozen = tuple(sorted(dims.items()))
     T = max(max(len(s) for s in sequences), pad_to)
     T = -(-T // block) * block

@@ -147,7 +147,7 @@ def run_cell(args, *, require_tpu: bool = True, conf: dict = None,
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import cells, configs, schedule, trace_reduce, weights
+    from bench import cells, configs, kinds, schedule, trace_reduce
     from bench.correct import draw_sample, served_gap, verdict
     from bench.driver import drive, serve_history
     from bench.peaks import peaks_for
@@ -171,7 +171,7 @@ def run_cell(args, *, require_tpu: bool = True, conf: dict = None,
     phases = {"start": time.perf_counter() - T_START}
     with CompileClock() as setup_clock:
         params = jax.block_until_ready(
-            weights.program_params(args.seed, dims, dtype))
+            kinds.module(dims["kind"]).program_params(args.seed, dims, dtype))
         phases["weights"] = time.perf_counter() - T_START
         eng = RealExecutionEngine(cfg, params, make_store(conf, mix, cfg),
                                   max_len=max_len, dtype=dtype)

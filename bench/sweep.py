@@ -38,7 +38,7 @@ def main(argv=None) -> int:
     a = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
-    from bench import cells, configs, schedule, weights
+    from bench import cells, configs, kinds, schedule
     from bench.compile_clock import use_compile_cache
     from bench.driver import drive, serve_history
     from bench.quantile import percentile
@@ -51,8 +51,8 @@ def main(argv=None) -> int:
     base = schedule.load_mix(cell.traffic)
     cfg = configs.model_config(conf)
     dtype = jnp.dtype(conf["serve"]["dtype"])
-    params = jax.block_until_ready(
-        weights.program_params(a.seeds[0], conf["dims"], dtype))
+    params = jax.block_until_ready(kinds.module(
+        conf["dims"]["kind"]).program_params(a.seeds[0], conf["dims"], dtype))
     eng = RealExecutionEngine(cfg, params, make_store(conf, base, cfg),
                               max_len=conf["serve"]["max_len"], dtype=dtype)
     warm(eng, base, conf["dims"]["vocab"])

@@ -1,5 +1,6 @@
 """Shared pieces of the benchmark's CPU tests: the checkout root on the
-path, and a reduced cell that runs end to end in seconds."""
+path, and a reduced cell (``bench/configs/tiny.json`` and the mix below)
+that runs end to end in seconds."""
 import os
 import sys
 
@@ -10,12 +11,9 @@ for p in (ROOT, os.path.join(ROOT, "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-TINY_DIMS = {"layers": 2, "d_model": 128, "heads": 4, "kv_heads": 2,
-             "head_dim": 32, "d_ff": 256, "vocab": 512, "window": None,
-             "rope_theta": 5e6, "norm_eps": 1e-5}
-TINY_CONF = {"name": "tiny", "source": "test", "reference": "dense_gqa",
-             "dims": TINY_DIMS,
-             "serve": {"dtype": "bfloat16", "max_len": 128}}
+from bench import configs  # noqa: E402
+
+TINY_CONF = configs.load("tiny")
 TINY_MIX = {"name": "tiny", "turns": 3, "first_prompt_tokens": [32, 48, 64],
             "append_tokens": 16, "output_tokens": 4, "gap_mean_s": 0.3,
             "rate_req_per_s": 6.0, "nominal_turn_s": 0.05, "history_s": 2,

@@ -8,7 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import configs, weights
+from bench import configs
+from bench.kinds import dense_gqa as weights
 from bench.references import dense_gqa
 from conftest import TINY_CONF
 from repro.models.transformer import decode_step, prefill
